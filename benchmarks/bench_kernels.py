@@ -1,6 +1,7 @@
-"""Kernel micro-benchmarks: jnp production paths (wall time on this CPU) and
-Pallas kernels in interpret mode (correctness-path latency; real TPU numbers
-come from the roofline projection in EXPERIMENTS.md §Perf)."""
+"""Kernel micro-benchmarks: jnp production paths and Pallas kernels in
+interpret mode, timed on the host CPU. These are correctness-path
+latencies, not TPU numbers: device timings come only from a run on the
+chip (`chip_smoke.py`)."""
 from __future__ import annotations
 
 import time

@@ -17,6 +17,7 @@ from repro.configs.bit1 import IO_KNOBS, cpu_config
 from repro.core import EngineConfig, Series
 from repro.core.darshan import MONITOR
 from repro.ckpt.checkpoint import restore_checkpoint, save_checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pic.simulation import (diagnostics, init_sim, pic_run_chunk,
                                   write_diagnostics_openpmd,
                                   write_particle_dump_openpmd)
@@ -33,6 +34,7 @@ def main(argv=None):
                     help="paper-size divisor (100K cells / scale)")
     ap.add_argument("--n-io-ranks", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     workdir = pathlib.Path(tempfile.mkdtemp(prefix="repro-pic-"))
     cfg = cpu_config(args.scale)

@@ -65,6 +65,15 @@ def _leaf_chunks(arr: np.ndarray, n_ranks: int):
             yield r, (lo,) + (0,) * (arr.ndim - 1), arr[lo:hi]
 
 
+def _distinct_boxes(leaf) -> list:
+    """One addressable shard per distinct box of a device array, in offset
+    order: the replicas of a box are written once. Empty for host leaves."""
+    boxes = {}
+    for sh in getattr(leaf, "addressable_shards", ()):
+        boxes.setdefault(tuple(sl.start or 0 for sl in sh.index), sh)
+    return [boxes[k] for k in sorted(boxes)]
+
+
 def save_checkpoint(directory, state, step: int, *, n_io_ranks: int = 8,
                     engine_config: EngineConfig = EngineConfig(),
                     extra_attrs: Optional[dict] = None,
@@ -89,10 +98,11 @@ def save_checkpoint(directory, state, step: int, *, n_io_ranks: int = 8,
     rings stay mapped across saves (the plane inherits its own transport;
     `transport` applies to the spawn-per-save path).
 
-    `device_compress=True` byte-shuffles sharded device leaves ON-CHIP
+    `device_compress=True` byte-shuffles device leaves ON-CHIP
     (repro.core.compression.device_precondition) before the writer hand-
     off — with parallel_io the workers receive pre-shuffled bytes over
-    the shm rings and pay only the LZ stage. Unsharded/host leaves and
+    the shm rings and pay only the LZ stage. A sharded leaf is written
+    one box per distinct shard (replicas once). Host leaves, scalars and
     bfloat16 (raw uint16 storage) keep the host path."""
     directory = pathlib.Path(str(directory))
     directory.mkdir(parents=True, exist_ok=True)
@@ -126,10 +136,17 @@ def save_checkpoint(directory, state, step: int, *, n_io_ranks: int = 8,
         for name, leaf in flat.items():
             dev_ok = (use_dev and "bfloat16" not in str(leaf.dtype)
                       and getattr(leaf, "ndim", 0) > 0)
-            if hasattr(leaf, "addressable_shards") and len(leaf.addressable_shards) > 1:
+            boxes = _distinct_boxes(leaf)
+            if len(boxes) == 1:
+                # replicated, or on one device: write one copy as a leaf
+                leaf = boxes[0].data
+            elif boxes:
                 gshape = tuple(leaf.shape)
-                for sh in leaf.addressable_shards:
-                    off = tuple(sl.start or 0 for sl in sh.index) if sh.index else ()
+                for i, sh in enumerate(boxes):
+                    off = tuple(sl.start or 0 for sl in sh.index)
+                    # writer rank from the box's position, not the device
+                    # id: ids need not be below n_io_ranks
+                    rank = i * n_io_ranks // len(boxes)
                     if dev_ok:
                         # on-chip bitshuffle per shard BEFORE the writer
                         # handoff: downstream (threads or shm workers)
@@ -140,12 +157,12 @@ def save_checkpoint(directory, state, step: int, *, n_io_ranks: int = 8,
                                        CTR.COMPRESS_DEVICE_BYTES,
                                        inc=float(chunk.device_bytes))
                         w.put(f"state/{name}", chunk, global_shape=gshape,
-                              offset=off, rank=sh.device.id)
+                              offset=off, rank=rank)
                     else:
                         w.put(f"state/{name}", _to_storage(np.asarray(sh.data)),
-                              global_shape=gshape, offset=off,
-                              rank=sh.device.id)
-            elif dev_ok and C.is_device_array(leaf):
+                              global_shape=gshape, offset=off, rank=rank)
+                continue
+            if dev_ok and C.is_device_array(leaf):
                 # single-shard device leaf: keep it on-device — the engine
                 # preconditions it itself (cfg.device_compress is set)
                 w.put(f"state/{name}", leaf, global_shape=tuple(leaf.shape),

@@ -454,16 +454,6 @@ class PreshuffledChunk:
         return n
 
 
-def _device_byte_view(arr):
-    """uint8 [nbytes] view of a device array's raw bytes, on-device."""
-    import jax
-    import jax.numpy as jnp
-    flat = arr.reshape(-1)
-    if flat.dtype == jnp.uint8:
-        return flat
-    return jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
-
-
 def _device_minmax(arr):
     """Launch the min/max reduction on-device (async); returns lazily-
     materialized scalars or None for dtypes without an order."""
@@ -480,20 +470,34 @@ def _device_shuffled_blocks(arr, block: int, itemsize: int, interpret):
     """Submit the per-codec-block on-chip shuffles and start each block's
     async D2H — the device queue runs ahead of the host. Returns
     (blocks=[(jax_block, was_shuffled)], nbytes, device_bytes, minmax)."""
+    from repro.kernels import auto_interpret
     from repro.kernels.bitshuffle import ops as bops
-    byts = _device_byte_view(arr)
-    nbytes = int(byts.shape[0])
+    if interpret is None:
+        interpret = auto_interpret()
+    nbytes = int(arr.size) * itemsize
     minmax = _device_minmax(arr)
     blocks = []
     device_bytes = 0
     for i in range(0, max(nbytes, 1), block):
-        s = byts[i:i + block]
-        blen = int(s.shape[0])
-        # mirror the host byte_shuffle no-op cases exactly so payloads are
-        # bit-compatible: itemsize 1 or a non-multiple tail pass through
+        blen = min(block, nbytes - i)
+        # a block's bytes come from the items that hold them, never from a
+        # byte view of the whole array (see bops._items); mirror the host
+        # byte_shuffle no-op cases exactly so payloads are bit-compatible:
+        # itemsize 1 or a non-multiple block passes through
+        first, skip = divmod(i, itemsize)
         shuf = itemsize > 1 and blen > 0 and blen % itemsize == 0
+        if shuf and not skip:
+            s = bops.shuffled_items(arr, first, n_items=blen // itemsize,
+                                    interpret=interpret)
+        else:   # only when the codec block is not a multiple of itemsize
+            n_items = -(-(skip + blen) // itemsize)
+            s = bops.item_bytes(arr, first, n_items=n_items)
+            if skip or n_items * itemsize != blen:
+                s = s[skip:skip + blen]
+            if shuf:
+                s = bops.shuffle_block(s, itemsize=itemsize,
+                                       interpret=interpret)
         if shuf:
-            s = bops.shuffle_block(s, itemsize=itemsize, interpret=interpret)
             device_bytes += blen
         s.copy_to_host_async()      # block k's D2H overlaps block k+1's work
         blocks.append((s, shuf))

@@ -4,16 +4,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.deposit.kernel import TILE_C, TILE_P, deposit_tpu
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def deposit(x, w, alive, *, n_cells: int, dx: float,
             interpret: bool | None = None) -> jax.Array:
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = auto_interpret() if interpret is None else interpret
     n = x.shape[0]
     pad_p = (-n) % TILE_P
     # park padded particles far outside the grid: clipped to the last cell

@@ -4,17 +4,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_tpu
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, qc: int = 512,
                     kc: int = 512, interpret: bool | None = None):
     """q/k/v: [B,S,H,D] (H(q) == H(kv); GQA callers expand first)."""
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = auto_interpret() if interpret is None else interpret
     B, S, H, D = q.shape
     qc = min(qc, S)
     kc = min(kc, S)

@@ -4,18 +4,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.ssd_scan.kernel import DEFAULT_CHUNK, ssd_scan_tpu
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def ssd_scan(x, dt, A, B, C, D, *, chunk: int = DEFAULT_CHUNK,
              interpret: bool | None = None):
     """Same contract as models.ssm.ssd_chunked (y only). x:[b,s,h,p],
     dt:[b,s,h], A/D:[h], B/C:[b,s,n]."""
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = auto_interpret() if interpret is None else interpret
     b, s, h, p = x.shape
     chunk = min(chunk, s)
     pad = (-s) % chunk

@@ -77,8 +77,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, tuning=None,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):      # older JAX: one dict per device
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     case = spec["case"]
     rep = build_report(arch=arch, shape=shape_name, mesh_name=mesh_kind,

@@ -9,16 +9,12 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across JAX versions: newer releases take (and want)
-    explicit axis_types; older ones (<= 0.4.x) reject the kwarg and have no
-    jax.sharding.AxisType at all."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, *, devices=None):
+    """The one mesh constructor: every axis `Auto`, so the compiler
+    partitions whatever the shardings leave open."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(*, multi_pod: bool = False, devices=None):
@@ -48,7 +44,7 @@ def make_debug_mesh(*, multi_pod: bool = False, devices=None):
                 f"debug mesh needs an even device count, got {n}")
         shape = (n // 2, 2)
         axes = ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def mesh_summary(mesh) -> dict:

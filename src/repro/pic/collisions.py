@@ -5,6 +5,8 @@ coefficient). Each MC event transfers weight from the neutral species to a
 newly spawned electron/ion pair."""
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -29,8 +31,8 @@ def ionize(key, electrons: Species, ions: Species, neutrals: Species,
     event = (u < p) & (neutrals.alive > 0)
 
     # neutral dies
-    new_neutrals = neutrals._replace(
-        alive=jnp.where(event, 0.0, neutrals.alive))
+    new_neutrals = dataclasses.replace(
+        neutrals, alive=jnp.where(event, 0.0, neutrals.alive))
 
     # electron + ion inherit position/weight; thermal kick for the electron
     kv = jax.random.fold_in(key, 1)

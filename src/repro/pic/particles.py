@@ -3,19 +3,22 @@ mover — BIT1 is 1D3V: one spatial dim, three velocity dims."""
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 
-class Species(NamedTuple):
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class Species:
     x: jnp.ndarray          # [C] position
     v: jnp.ndarray          # [C, 3] velocity (vx drives motion)
     w: jnp.ndarray          # [C] macro-particle weight
     alive: jnp.ndarray      # [C] float mask (1.0 alive / 0.0 dead)
-    charge: float
-    mass: float
+    # static: part of the traced signature, never device arrays, so a state
+    # that comes back from jit or from a checkpoint compiles to the same step
+    charge: float = dataclasses.field(metadata=dict(static=True))
+    mass: float = dataclasses.field(metadata=dict(static=True))
 
     @property
     def capacity(self):
@@ -54,7 +57,7 @@ def push(sp: Species, E_at_p, dt: float, L: float, *,
         wall = jnp.sum(jnp.where(hit, sp.w, 0.0))
         alive = jnp.where(hit, 0.0, sp.alive)
         x = jnp.clip(x, 0.0, L * (1.0 - 1e-7))
-    return sp._replace(x=x, v=v, alive=alive), wall
+    return dataclasses.replace(sp, x=x, v=v, alive=alive), wall
 
 
 def spawn(sp: Species, new_x, new_v, new_w, n_new_mask) -> Species:
@@ -78,4 +81,5 @@ def spawn(sp: Species, new_x, new_v, new_w, n_new_mask) -> Species:
     w = w.at[slot].set(new_w)
     al = al.at[slot].set(1.0)
     dropped = jnp.sum(n_new_mask & ~ok)
-    return sp._replace(x=x[:C], v=v[:C], w=w[:C], alive=al[:C]), dropped
+    return dataclasses.replace(sp, x=x[:C], v=v[:C], w=w[:C],
+                               alive=al[:C]), dropped

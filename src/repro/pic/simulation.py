@@ -180,10 +180,10 @@ def open_diagnostic_series(path, *, n_io_ranks: int = 8, async_io: bool = True,
     snapshot queue (`async_commit`), so the push/deposit loop sees
     neither compression nor commit latency.
 
-    `device_compress=True` turns on the on-chip compression precondition:
-    jax.Array chunks stored on the series are byte-shuffled on the
-    accelerator (the Pallas bitshuffle kernel) before the host runs only
-    the cheap LZ stage."""
+    `device_compress=True` turns on the on-chip compression precondition
+    for jax.Array chunks stored on the series. The writers in this module
+    store host copies (`np.asarray`), so on the PIC path it has no effect
+    yet."""
     from repro.core.bp_engine import EngineConfig
     from repro.core.openpmd import Series
     if engine_config is None:

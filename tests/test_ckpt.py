@@ -113,13 +113,13 @@ def test_elastic_resharding_subprocess(tmpdir_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt.checkpoint import save_checkpoint, restore_sharded
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh1 = compat_make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh1 = make_mesh((2, 2), ("data", "model"))
         sh1 = NamedSharding(mesh1, P("data", "model"))
         w = jax.device_put(np.arange(64, dtype=np.float32).reshape(8, 8), sh1)
         save_checkpoint(r"{tmpdir_path}", {{"w": w}}, 3, n_io_ranks=4)
 
-        mesh2 = compat_make_mesh((4, 1), ("data", "model"))
+        mesh2 = make_mesh((4, 1), ("data", "model"))
         sh2 = NamedSharding(mesh2, P("model", "data"))
         like = {{"w": jax.ShapeDtypeStruct((8, 8), np.float32)}}
         out, step = restore_sharded(r"{tmpdir_path}", like, {{"w": sh2}})

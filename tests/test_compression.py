@@ -326,3 +326,23 @@ def test_device_precondition_roundtrip_and_stats():
     assert chunk.vmax == float(np.max(arr))
     buf = C.array_payload_preshuffled(chunk, "blosc")
     assert buf == C.array_payload(arr, "blosc", block=64 * 1024)
+
+
+@pytest.mark.parametrize("shape,dtype,block", [
+    ((50_000, 3), np.float32, 64 * 1024),     # narrow minor dim, row-split blocks
+    ((4_001,), np.int32, 4_002),              # blocks that split items
+    ((8, 64, 2), np.float32, 1024),
+    ((0, 3), np.float32, 1024),               # empty
+    ((3_000,), np.uint8, 1024),               # itemsize 1: never shuffled
+])
+def test_device_payload_matches_host_any_layout(shape, dtype, block):
+    """Compressible data, so no block is stored raw (a raw store keeps
+    the pre-shuffle flag, and then only the decoded arrays agree)."""
+    jnp = pytest.importorskip("jax.numpy")
+    size = int(np.prod(shape))
+    arr = (np.arange(size) % 7).astype(dtype).reshape(shape)
+    host = C.array_payload(arr, "blosc", block=block)
+    dev, _ = C.device_array_payload(jnp.asarray(arr), "blosc", block=block)
+    assert dev == host
+    chunk = C.device_precondition(jnp.asarray(arr), block=block)
+    assert C.array_payload_preshuffled(chunk, "blosc") == host

@@ -52,8 +52,7 @@ def test_sst_streaming_roundtrip():
 
 def test_opt_moments_shard_over_pod():
     from repro.train.state import train_state_shardings
-    mesh = jax.sharding.AbstractMesh((("pod", 2), ("data", 16),
-                                      ("model", 16)))
+    mesh = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     cfg = get_config("qwen3-4b")
     sh = train_state_shardings(cfg, mesh)
     m_spec = sh["opt"]["m"]["stack"]["layers"]["ffn"]["gate"]["w"].spec

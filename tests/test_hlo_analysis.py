@@ -68,8 +68,8 @@ def test_collectives_detected_subprocess():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.roofline.hlo_analysis import analyze
 
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         W = jax.ShapeDtypeStruct((256, 512), jnp.float32)
         X = jax.ShapeDtypeStruct((64, 256), jnp.float32)
         f = lambda w, x: jnp.sum((x @ w)**2)

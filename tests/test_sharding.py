@@ -8,9 +8,8 @@ from repro.launch.sharding import (attn_layout, cache_pspec_tree,
                                    param_pspec_tree)
 from repro.models import model as M
 
-# AbstractMesh on this JAX takes a single shape tuple of (name, size) pairs
-MESH = jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = jax.sharding.AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_attn_layout_per_arch():
