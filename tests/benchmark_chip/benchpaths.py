@@ -1,0 +1,8 @@
+"""Puts the repository root on `sys.path`, so that the tests of the chip
+benchmark import it as `benchmarks.chip`."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
