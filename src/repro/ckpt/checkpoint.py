@@ -25,6 +25,7 @@ import numpy as np
 from repro.core import compression as C
 from repro.core.bp_engine import BpReader, BpWriter, EngineConfig
 from repro.core.darshan import CTR, MONITOR
+from repro.core.dxt import TRACER
 
 SEP = "/"
 
@@ -251,12 +252,16 @@ def restore_sharded(directory, like, shardings, step: Optional[int] = None,
                 off = tuple((sl.start or 0) for sl in idx)
                 ext = tuple((sl.stop if sl.stop is not None else s) -
                             (sl.start or 0) for sl, s in zip(idx, _leaf.shape))
-                a = reader.read_var(step, _var, off, ext)
-                return _from_storage(a, _leaf.dtype)
+                # read and decode on the host; the copy to the device is
+                # the caller's
+                with TRACER.span("restore_fetch", path=_var):
+                    a = reader.read_var(step, _var, off, ext)
+                    return _from_storage(a, _leaf.dtype)
 
             if leaf.ndim == 0:
-                arr = _from_storage(reader.read_var(step, var),
-                                    leaf.dtype).reshape(())
+                with TRACER.span("restore_fetch", path=var):
+                    arr = _from_storage(reader.read_var(step, var),
+                                        leaf.dtype).reshape(())
                 out[name] = jax.device_put(arr, sh)
             else:
                 out[name] = jax.make_array_from_callback(leaf.shape, sh, fetch)

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.ckpt import checkpoint as CK
 from repro.core.bp_engine import EngineConfig
+from repro.core.dxt import TRACER
 
 
 class CheckpointManager:
@@ -113,6 +114,10 @@ class CheckpointManager:
     def save(self, state, step: int, *, force: bool = False):
         if not force and not self.should_save(step):
             return False
+        with TRACER.span("ckpt_save", path=str(self.dir)):
+            return self._save(state, step)
+
+    def _save(self, state, step: int) -> bool:
         self.wait()                                  # one write in flight max
 
         def snap(x):
@@ -128,20 +133,21 @@ class CheckpointManager:
 
         def job():
             try:
-                t0 = time.perf_counter()
-                CK.save_checkpoint(self.dir, host_state, step,
-                                   n_io_ranks=self.n_io_ranks,
-                                   engine_config=self.engine_config,
-                                   async_io=(self.engine_async
-                                             and not self.parallel_io),
-                                   parallel_io=self.parallel_io,
-                                   writer_plane=self._writer_plane(),
-                                   device_compress=self.device_compress)
-                self.stats["write_s"] += time.perf_counter() - t0
-                self.saved_steps.append(step)
-                # durability barrier passed (sealed md.idx + rename above):
-                # only now may older checkpoints be evicted
-                self._retain()
+                with TRACER.span("ckpt_write", path=str(self.dir)):
+                    t0 = time.perf_counter()
+                    CK.save_checkpoint(self.dir, host_state, step,
+                                       n_io_ranks=self.n_io_ranks,
+                                       engine_config=self.engine_config,
+                                       async_io=(self.engine_async
+                                                 and not self.parallel_io),
+                                       parallel_io=self.parallel_io,
+                                       writer_plane=self._writer_plane(),
+                                       device_compress=self.device_compress)
+                    self.stats["write_s"] += time.perf_counter() - t0
+                    self.saved_steps.append(step)
+                    # durability barrier passed (sealed md.idx + rename
+                    # above): only now may older checkpoints be evicted
+                    self._retain()
             except BaseException as e:               # noqa: BLE001
                 self._error = e
 
@@ -183,15 +189,17 @@ class CheckpointManager:
     def restore_latest(self, like, shardings=None, *, parallel: int = 0):
         """Newest valid checkpoint, or None if there is none. `parallel=N`
         fans each leaf's chunk reads over a ReaderPool."""
-        self.wait()
-        steps = CK.list_checkpoints(self.dir)
-        for step in reversed(steps):
-            try:
-                if shardings is not None:
-                    return CK.restore_sharded(self.dir, like, shardings,
-                                              step=step, parallel=parallel)
-                return CK.restore_checkpoint(self.dir, like, step=step,
-                                             parallel=parallel)
-            except Exception:                        # noqa: BLE001
-                continue
-        return None
+        with TRACER.span("restore", path=str(self.dir)):
+            self.wait()
+            steps = CK.list_checkpoints(self.dir)
+            for step in reversed(steps):
+                try:
+                    if shardings is not None:
+                        return CK.restore_sharded(self.dir, like, shardings,
+                                                  step=step,
+                                                  parallel=parallel)
+                    return CK.restore_checkpoint(self.dir, like, step=step,
+                                                 parallel=parallel)
+                except Exception:                    # noqa: BLE001
+                    continue
+            return None

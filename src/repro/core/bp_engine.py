@@ -212,9 +212,8 @@ def seal_md_record(md, idx, md_off: int, step: int, blob: bytes,
     returning (md.0 fsynced BEFORE the idx record exists, so a validated
     idx record always points at durable metadata); otherwise bytes reach
     the OS and the fsync is deferred to close. Returns the new md offset."""
-    ts = time.perf_counter()
     with TRACER.span("seal", path=getattr(idx, "path", ""),
-                     length=len(blob)):
+                     length=len(blob), observe=True):
         md.write(blob)
         crc = zlib.crc32(blob) & 0xFFFFFFFF
         rec = IDX_RECORD.pack(step, md_off, len(blob), crc, 1,
@@ -227,9 +226,6 @@ def seal_md_record(md, idx, md_off: int, step: int, blob: bytes,
             idx.write(rec)
             md.flush()   # bytes reach the OS; fsync deferred to close
             idx.flush()
-    if METRICS.enabled:
-        METRICS.observe("seal", time.perf_counter() - ts, nbytes=len(blob),
-                        key=getattr(idx, "path", ""))
     return md_off + len(blob)
 
 
@@ -394,11 +390,10 @@ class BpWriter:
 
         def agg_job(agg, items):
             try:
-                tc = time.perf_counter()
                 dpath = str(self.path / f"data.{agg}")
                 payloads, metas = [], []
                 with TRACER.span("compress", path=f"data.{agg}",
-                                 rank=agg) as sp:
+                                 rank=agg, observe=True) as sp:
                     for name, rank, offset, arr, codec in items:
                         payload, shape, stats, dstats = encode_chunk(
                             arr, codec, self.cfg.compression_block,
@@ -410,11 +405,7 @@ class BpWriter:
                         metas.append((name, rank, offset, shape,
                                       len(payload), stats))
                     sp.length = sum(len(p) for p in payloads)
-                tcomp = time.perf_counter() - tc
-                if METRICS.enabled:
-                    METRICS.observe(
-                        "compress", tcomp, key=f"data.{agg}",
-                        nbytes=sum(len(p) for p in payloads))
+                tcomp = sp.seconds
                 base = self.subfiles.append(agg, b"".join(payloads))
             except Exception as e:   # noqa: BLE001
                 errors.append(e)

@@ -42,7 +42,6 @@ import zlib
 import numpy as np
 
 from repro.core.dxt import TRACER
-from repro.core.metrics import METRICS
 
 MAGIC = b"JBPC"
 HEADER = struct.Struct("<4sBBHII")  # magic, codec_id, itemsize, flags, raw, comp
@@ -510,9 +509,9 @@ def device_precondition(arr, *, block: int = DEFAULT_BLOCK,
     bytes on host as a `PreshuffledChunk` (the shm-transportable form the
     ParallelBpWriter hands its workers — they skip the shuffle). Min/max
     chunk stats ride along from a device-side reduction."""
-    t0 = time.perf_counter()
     dt = np.dtype(arr.dtype)
-    with TRACER.span("device_shuffle", length=int(arr.size) * dt.itemsize):
+    with TRACER.span("device_shuffle", length=int(arr.size) * dt.itemsize,
+                     observe=True):
         blocks, nbytes, dev_bytes, minmax = _device_shuffled_blocks(
             arr, block, dt.itemsize, interpret)
         host = np.empty(nbytes, np.uint8)
@@ -521,11 +520,8 @@ def device_precondition(arr, *, block: int = DEFAULT_BLOCK,
             h = np.asarray(s)
             host[pos:pos + h.size] = h
             pos += h.size
-    vmin = float(np.asarray(minmax[0])) if minmax else 0.0
-    vmax = float(np.asarray(minmax[1])) if minmax else 0.0
-    if METRICS.enabled:
-        METRICS.observe("device_shuffle", time.perf_counter() - t0,
-                        nbytes=nbytes)
+        vmin = float(np.asarray(minmax[0])) if minmax else 0.0
+        vmax = float(np.asarray(minmax[1])) if minmax else 0.0
     return PreshuffledChunk(host, dt, arr.shape, block, vmin, vmax,
                             device_bytes=dev_bytes)
 
@@ -566,8 +562,8 @@ def device_array_payload(arr, codec: str, block: int = DEFAULT_BLOCK, *,
             stats.vmin = float(np.min(a))
             stats.vmax = float(np.max(a))
         return array_payload(a, codec, block), stats
-    t0 = time.perf_counter()
-    with TRACER.span("device_shuffle", length=int(arr.size) * dt.itemsize):
+    with TRACER.span("device_shuffle", length=int(arr.size) * dt.itemsize,
+                     observe=True):
         blocks, nbytes, device_bytes, minmax = _device_shuffled_blocks(
             arr, block, dt.itemsize, interpret)
         out = []
@@ -580,7 +576,6 @@ def device_array_payload(arr, codec: str, block: int = DEFAULT_BLOCK, *,
             t2 = time.perf_counter()
             lz_s += t2 - t1
             lz_last = t2 - t1
-    wall = time.perf_counter() - t0
     stats = DeviceStats(
         device_bytes=device_bytes,
         # LZ seconds that ran while a later block was still in the device/
@@ -588,6 +583,4 @@ def device_array_payload(arr, codec: str, block: int = DEFAULT_BLOCK, *,
         overlap_s=lz_s - lz_last if len(blocks) > 1 else 0.0,
         vmin=float(np.asarray(minmax[0])) if minmax else 0.0,
         vmax=float(np.asarray(minmax[1])) if minmax else 0.0)
-    if METRICS.enabled:
-        METRICS.observe("device_shuffle", wall, nbytes=nbytes)
     return b"".join(out), stats

@@ -21,8 +21,9 @@ whole I/O stack, so the trace is explicit rather than LD_PRELOADed:
     rebases everything onto one global wall-clock axis — merged timelines
     are comparable across processes (and across hosts, to NTP accuracy).
 
-Cost discipline: tracing OFF is one attribute load + branch per op (the
-hot paths check `TRACER.enabled` before calling anything). Tracing ON is
+Cost discipline: for `InstrumentedFile` ops, tracing OFF is one
+attribute load + branch per op (the hot paths check `TRACER.enabled`
+before calling anything); spans cost more, see below. Tracing ON is
 bounded memory — per-thread ring buffers of `capacity` events each;
 when a ring fills the OLDEST event is dropped and counted, never blocking
 an I/O path (`bench_darshan_costs.run_tracing_overhead` holds the write
@@ -42,25 +43,49 @@ Exports:
 Enable programmatically (`TRACER.enable()`) or via the environment
 (`JBP_DXT=1`, inherited by spawned writer workers); `JBP_DXT_CAPACITY`
 overrides the per-thread ring size.
+
+One span record, three sinks. Every `TRACER.span(op)` also opens a
+profiler TraceMe named `jbp.<op>` (`jax.profiler.TraceAnnotation`),
+whether or not the ring is enabled: under any `jax.profiler` trace the
+span lands on the `/host:CPU` plane, one line per thread, on the clock of
+the device lines. The same exit feeds the ring (when `enabled`) and,
+where the call site asks for it (`observe=True`), the `METRICS`
+histogram under (op, path). This module never imports jax: the
+annotation hook is looked up only once jax is already loaded in the
+process (a jax that lacks it fails the span loudly), and in a process
+with no profiler session, such as a spawned writer worker (the profiler
+records the coordinator only), no TraceMe is made. Span cost: with no
+profiler session and the ring off, a span is one `_Span` object, one
+profiler check and two clock reads, so spans stay at step, leaf or call
+granularity, never inside per-chunk loops.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Optional
 
+from repro.core.metrics import METRICS
+
 DEFAULT_CAPACITY = int(os.environ.get("JBP_DXT_CAPACITY", 1 << 15))
 
 # span vocabulary (the step-lifecycle ops, distinct from the POSIX ops
-# recorded by InstrumentedFile): keep these stable — jbpdxt and the
-# Chrome export group by them
+# recorded by InstrumentedFile): keep these stable — jbpdxt, the Chrome
+# export and the benchmark's per-layer metrics (as `jbp.<op>`) read or
+# group by them. Span ops never reuse a POSIX op's name: the exports
+# tell the two kinds apart by op alone
 SPAN_OPS = ("snapshot", "compress", "transport", "prepare", "seal",
             "commit", "pipeline", "cache_fetch", "serve", "read_task",
-            "device_shuffle")
+            "device_shuffle", "pic_chunk", "diagnostics", "dump_copy",
+            "series_flush", "series_drain", "ckpt_save", "ckpt_write",
+            "restore", "restore_fetch")
 POSIX_OPS = ("open", "read", "write", "seek", "flush", "fsync", "close")
+# profiler name of a span: TRACE_PREFIX + op
+TRACE_PREFIX = "jbp."
 
 
 class _ThreadBuf:
@@ -76,43 +101,50 @@ class _ThreadBuf:
 
 
 class _Span:
-    """Context manager recording one lifecycle span on exit. `length` may
-    be set inside the block (e.g. bytes moved by a transport span)."""
+    """Context manager for one lifecycle span: a profiler TraceMe while
+    the block runs (when a profiler session is on), then on exit one ring
+    event (when the tracer is enabled) and, for `observe` spans that exit
+    without an error, one `METRICS` observation under (op, path). `length`
+    may be set inside the block (e.g. bytes moved by a transport span);
+    `seconds` is the span's duration once it has exited."""
 
-    __slots__ = ("_tr", "op", "path", "rank", "length", "_t0")
+    __slots__ = ("_tr", "op", "path", "rank", "length", "_observe", "_tm",
+                 "t0", "t1")
 
     def __init__(self, tr: "DxtTracer", op: str, path: str, rank: int,
-                 length: int):
+                 length: int, observe: bool):
         self._tr = tr
         self.op = op
         self.path = path
         self.rank = rank
         self.length = length
+        self._observe = observe
+        self._tm = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        ann = self._tr._annotation()
+        if ann is not None and ann.is_enabled():
+            self._tm = ann(TRACE_PREFIX + self.op)
+            self._tm.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *a):
-        self._tr.record(self.rank, self.path, self.op, 0, self.length,
-                        self._t0, time.perf_counter())
+    def __exit__(self, exc_type, *a):
+        self.t1 = time.perf_counter()
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
+        tr = self._tr
+        if tr.enabled:
+            tr.record(self.rank, self.path, self.op, 0, self.length,
+                      self.t0, self.t1)
+        if self._observe and exc_type is None and METRICS.enabled:
+            METRICS.observe(self.op, self.t1 - self.t0, nbytes=self.length,
+                            key=self.path)
         return False
 
-
-class _NullSpan:
-    """The tracing-off span: no clock reads, no record. One shared
-    instance; `length` writes are absorbed by __slots__ on each use."""
-
-    __slots__ = ("length",)
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 class DxtTracer:
@@ -137,6 +169,8 @@ class DxtTracer:
         # (src, rank, path, op, offset, length, t0, t1)
         self._foreign: list[tuple] = []
         self._foreign_dropped = 0
+        # the profiler hook: None until jax is loaded
+        self._ann = None
         self._stamp_epoch()
 
     def _stamp_epoch(self):
@@ -191,12 +225,25 @@ class DxtTracer:
             buf.dropped += 1
         ev.append((rank, path, op, offset, length, t0, t1))
 
-    def span(self, op: str, path: str = "", rank: int = 0, length: int = 0):
-        """Lifecycle span context manager; a shared no-op when disabled
-        (callers on hot paths may also branch on `TRACER.enabled`)."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, op, path, rank, length)
+    def span(self, op: str, path: str = "", rank: int = 0, length: int = 0,
+             *, observe: bool = False):
+        """Lifecycle span context manager (see `_Span`). `observe=True`
+        feeds the `METRICS` histogram from the span's own exit, under op
+        `op` and key `path`, with `length` as its bytes."""
+        return _Span(self, op, path, rank, length, observe)
+
+    def _annotation(self):
+        """`jax.profiler.TraceAnnotation` once jax is loaded in this
+        process, else None; never imports jax itself. A loaded jax whose
+        profiler lacks it raises: spans must not drop off the trace in
+        silence."""
+        ann = self._ann
+        if ann is None:
+            prof = sys.modules.get("jax.profiler")
+            if prof is None:
+                return None
+            ann = self._ann = prof.TraceAnnotation
+        return ann
 
     @staticmethod
     def now() -> float:

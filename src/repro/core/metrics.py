@@ -178,7 +178,7 @@ class _Shard:
 
 class _NullTimer:
     """The metrics-off timer: no clock reads, no record. One shared
-    instance, like dxt's _NULL_SPAN."""
+    instance."""
 
     __slots__ = ("nbytes",)
 

@@ -54,6 +54,7 @@ from typing import Any, Optional
 import numpy as np
 
 from repro.core.bp_engine import BpReader, BpWriter, EngineConfig
+from repro.core.dxt import TRACER
 
 OPENPMD_VERSION = "1.1.0"
 BASE_PATH = "/data/%T/"
@@ -287,31 +288,33 @@ class Series:
         """Write all dirty record components as one engine step."""
         if not self._dirty:
             return None
-        by_step: dict[int, list[RecordComponent]] = {}
-        for rc in self._dirty:
-            step = int(rc._path.split("/")[2])
-            by_step.setdefault(step, []).append(rc)
-        w = self._get_writer()
-        prof = None
-        for step in sorted(by_step):
-            w.begin_step(step)
-            it = self.iterations[step]
-            w.set_attribute(f"/data/{step}/time", it.time)
-            w.set_attribute(f"/data/{step}/dt", it.dt)
-            for rc in by_step[step]:
-                for arr, off, rank in rc._chunks:
-                    w.put(rc._path, arr, global_shape=rc._global_extent,
-                          offset=off, rank=rank, codec=rc.codec)
-                rc._chunks.clear()
-            prof = w.end_step()
-        self._dirty.clear()
-        return prof
+        with TRACER.span("series_flush", path=str(self.path)):
+            by_step: dict[int, list[RecordComponent]] = {}
+            for rc in self._dirty:
+                step = int(rc._path.split("/")[2])
+                by_step.setdefault(step, []).append(rc)
+            w = self._get_writer()
+            prof = None
+            for step in sorted(by_step):
+                w.begin_step(step)
+                it = self.iterations[step]
+                w.set_attribute(f"/data/{step}/time", it.time)
+                w.set_attribute(f"/data/{step}/dt", it.dt)
+                for rc in by_step[step]:
+                    for arr, off, rank in rc._chunks:
+                        w.put(rc._path, arr, global_shape=rc._global_extent,
+                              offset=off, rank=rank, codec=rc.codec)
+                    rc._chunks.clear()
+                prof = w.end_step()
+            self._dirty.clear()
+            return prof
 
     def drain(self):
         """Durability barrier: with async_io, block until every flushed
         iteration's md.idx record is sealed on disk. No-op for sync."""
         if self._writer is not None and hasattr(self._writer, "drain"):
-            self._writer.drain()
+            with TRACER.span("series_drain", path=str(self.path)):
+                self._writer.drain()
 
     def close(self):
         """Flush remaining iterations and shut the engine down. The writer

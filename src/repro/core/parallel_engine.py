@@ -375,9 +375,8 @@ def _worker_main(w: int, path_str, n_writers: int, cfg, task_q, result_q,
             # the shard, and a stale counter would desync every later
             # commit ("worker stays alive" requires this)
             rec_off = shard.tell()
-            tseal = time.perf_counter()
             with TRACER.span("seal", path=f"md.{w}.shard", rank=w,
-                             length=len(blob)):
+                             length=len(blob), observe=True):
                 shard.write(SHARD_HDR.pack(step, len(blob), crc))
                 shard.write(blob)
                 if cfg.fsync_policy == "step":
@@ -386,9 +385,6 @@ def _worker_main(w: int, path_str, n_writers: int, cfg, task_q, result_q,
                 else:
                     subfiles.flush_one(w)
                     shard.flush()  # coordinator reads the record back NOW
-            if METRICS.enabled:
-                METRICS.observe("seal", time.perf_counter() - tseal,
-                                nbytes=len(blob), key=f"md.{w}.shard")
             info = {"shard_off": rec_off,
                     "shard_len": SHARD_HDR.size + len(blob), "crc": crc,
                     "compress_s": tcomp, "bytes_stored": off - base,

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dxt import TRACER
 from repro.pic import collisions, fields, grid
 from repro.pic.particles import Species, init_species, push
 
@@ -220,9 +221,11 @@ def run_with_diagnostics(state: PicState, cfg: PicConfig, series=None, *,
                      (run-time diagnostics without a consumer thread).
     """
     for c in range(n_chunks):
-        state = pic_run_chunk(state, cfg, steps_per_chunk)
-        step = int(state.step)
-        diag = diagnostics(state, cfg)
+        with TRACER.span("pic_chunk"):
+            state = pic_run_chunk(state, cfg, steps_per_chunk)
+            step = int(state.step)
+        with TRACER.span("diagnostics"):
+            diag = diagnostics(state, cfg)
         arrays = {k: v for k, v in diag.items() if isinstance(v, np.ndarray)}
         if series is not None:
             write_diagnostics_openpmd(series, state, cfg,
@@ -246,17 +249,22 @@ def run_with_diagnostics(state: PicState, cfg: PicConfig, series=None, *,
 
 def write_particle_dump_openpmd(series, state: PicState, cfg: PicConfig,
                                 *, n_io_ranks: int = 8):
-    """Full particle state (dmp analogue): species records chunked by rank."""
+    """Full particle state (dmp analogue): species records chunked by rank.
+    Every record is copied to the host first, in one span."""
     step = int(state.step)
     it = series.iterations[step]
-    for name, sp in (("e", state.electrons), ("D_plus", state.ions),
-                     ("D", state.neutrals)):
+    species_all = (("e", state.electrons), ("D_plus", state.ions),
+                   ("D", state.neutrals))
+    with TRACER.span("dump_copy", path=f"step.{step}") as span:
+        records = [{"position/x": np.asarray(sp.x),
+                    "momentum/x": np.asarray(sp.v[:, 0]),
+                    "momentum/y": np.asarray(sp.v[:, 1]),
+                    "momentum/z": np.asarray(sp.v[:, 2]),
+                    "weighting": np.asarray(sp.w * sp.alive)}
+                   for _, sp in species_all]
+        span.length = sum(a.nbytes for r in records for a in r.values())
+    for (name, sp), arrays in zip(species_all, records):
         species = it.particles[name]
-        arrays = {"position/x": np.asarray(sp.x),
-                  "momentum/x": np.asarray(sp.v[:, 0]),
-                  "momentum/y": np.asarray(sp.v[:, 1]),
-                  "momentum/z": np.asarray(sp.v[:, 2]),
-                  "weighting": np.asarray(sp.w * sp.alive)}
         C = sp.capacity
         per = max(C // n_io_ranks, 1)
         for rec_name, arr in arrays.items():

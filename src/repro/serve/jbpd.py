@@ -217,14 +217,10 @@ class ChunkCache:
                     raise fl.error
                 return fl.result
             try:
-                tf = time.perf_counter()
-                with TRACER.span("cache_fetch", path=series) as sp:
+                with TRACER.span("cache_fetch", path=series,
+                                 observe=True) as sp:
                     arr = fetch()
                     sp.length = arr.nbytes
-                if METRICS.enabled:
-                    METRICS.observe("cache_fetch",
-                                    time.perf_counter() - tf,
-                                    nbytes=arr.nbytes, key=series)
                 if arr.flags.writeable:        # cached objects are shared
                     arr = arr.copy()
                 arr.flags.writeable = False
@@ -564,12 +560,9 @@ class JbpDaemon:
                         break                  # client went away mid-stream
                     continue
                 try:
-                    tq = time.perf_counter()
-                    with TRACER.span("serve", path=str(op), rank=cid):
+                    with TRACER.span("serve", path=str(op), rank=cid,
+                                     observe=True):
                         res = self.server.query(hdr)
-                    if METRICS.enabled:
-                        METRICS.observe("serve", time.perf_counter() - tq,
-                                        key=str(op))
                 except BaseException as e:     # noqa: BLE001 — conn survives
                     send_msg(conn, {"ok": False,
                                     "error": {"kind": _error_kind(e),
