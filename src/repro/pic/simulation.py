@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.dxt import TRACER
 from repro.pic import collisions, fields, grid
+from repro.pic.histogram import species_histograms
 from repro.pic.particles import Species, init_species, push
 
 
@@ -126,14 +127,11 @@ def diagnostics(state: PicState, cfg: PicConfig, *, v_bins: int = 64) -> dict:
                      ("D", state.neutrals)):
         dens = grid.deposit_cic(sp.x, sp.w, sp.alive, cfg.n_cells, cfg.dx)
         out[f"density/{name}"] = np.asarray(dens)
-        vmag = jnp.linalg.norm(sp.v, axis=-1)
-        hist, _ = jnp.histogram(vmag, bins=v_bins, range=(0.0, 5.0),
-                                weights=sp.w * sp.alive)
-        out[f"vdist/{name}"] = np.asarray(hist)
-        energy = 0.5 * sp.mass * vmag**2
-        ehist, _ = jnp.histogram(energy, bins=v_bins, range=(0.0, 10.0),
-                                 weights=sp.w * sp.alive)
-        out[f"edist/{name}"] = np.asarray(ehist)
+        vdist, edist = species_histograms(
+            sp.v, sp.w, sp.alive, mass=sp.mass, bins=v_bins,
+            v_range=(0.0, 5.0), e_range=(0.0, 10.0))
+        out[f"vdist/{name}"] = np.asarray(vdist)
+        out[f"edist/{name}"] = np.asarray(edist)
         out[f"count/{name}"] = float(sp.count())
     out["wall_flux/e"] = float(state.wall_flux_e)
     out["wall_flux/i"] = float(state.wall_flux_i)

@@ -1,4 +1,5 @@
-"""Compile the write path's kernels for a described TPU v5e, at real widths.
+"""Compile the write path's kernels and the diagnostics' histogram program
+for a described TPU v5e, at real widths.
 
 Nothing runs: the TPU compiler refuses here what it would refuse on the
 chip (tiling, VMEM, HBM). The topology is described inside a fixture, so
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.bit1 import paper_config
 from repro.kernels.bitshuffle.kernel import byte_shuffle_block
 from repro.kernels.bitshuffle.ops import shuffled_items
+from repro.pic.histogram import species_histograms
 
 
 @pytest.fixture(scope="module")
@@ -62,4 +64,19 @@ def test_block_shuffle_never_relayouts_a_particle_record(one_chip, shape):
     compiled = shuffled_items.lower(arr, first, n_items=1 << 18,
                                     interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("capacity", [1 << 23, 1 << 25])
+def test_species_histograms_stay_in_blocks(one_chip, capacity):
+    """One species' speed and energy histograms at a chip's share of the
+    paper problem (2^23) and at paper_config (2^25) take under 64 MiB of
+    temp memory: no [C, 65] intermediate, and at 2^25 nothing of the
+    particle axis' length (128 MiB in float32)."""
+    v = jax.ShapeDtypeStruct((capacity, 3), jnp.float32, sharding=one_chip)
+    f = jax.ShapeDtypeStruct((capacity,), jnp.float32, sharding=one_chip)
+    compiled = species_histograms.lower(
+        v, f, f, mass=1836.0, bins=64, v_range=(0.0, 5.0),
+        e_range=(0.0, 10.0)).compile()
+    assert "jit_species_histograms" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
