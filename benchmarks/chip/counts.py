@@ -23,13 +23,18 @@ def shuffle_bytes(n_bytes: int) -> int:
     return 2 * n_bytes
 
 
+def split_axis(shape, parts: int) -> int | None:
+    """The first axis of `shape` that `parts` divides, or None."""
+    return next((i for i, s in enumerate(shape) if int(s) % parts == 0), None)
+
+
 def shard_shape(shape, parts: int) -> tuple:
     """`shape` cut into `parts` along its first axis that `parts` divides
     (FSDP/ZeRO-3 sharding of one leaf); scalars are replicated."""
     shape = tuple(int(s) for s in shape)
-    for i, s in enumerate(shape):
-        if s % parts == 0:
-            return shape[:i] + (s // parts,) + shape[i + 1:]
+    i = split_axis(shape, parts)
+    if i is not None:
+        return shape[:i] + (shape[i] // parts,) + shape[i + 1:]
     if not shape:
         return shape
     raise ValueError(f"no axis of {shape} is divisible by {parts}")

@@ -47,3 +47,22 @@ def blocks_off(payload: bytes, leaf_bytes: bytes, itemsize: int) -> int:
         else:
             off += 1
     return off + (done != len(leaf_bytes))
+
+
+def covers_once(boxes, shape) -> bool:
+    """Whether the (offset, extent) boxes tile an array of `shape`: each
+    inside it, no two overlapping, and together as many items as it has."""
+    def inside(off, ext):
+        return (len(off) == len(ext) == len(shape)
+                and all(0 <= o and e > 0 and o + e <= s
+                        for o, e, s in zip(off, ext, shape)))
+
+    def overlap(a, b):
+        return all(oa < ob + eb and ob < oa + ea
+                   for oa, ea, ob, eb in zip(*a, *b))
+
+    boxes = [(tuple(o), tuple(e)) for o, e in boxes]
+    return (all(inside(o, e) for o, e in boxes)
+            and sum(int(np.prod(e)) for _, e in boxes) == int(np.prod(shape))
+            and not any(overlap(a, b) for i, a in enumerate(boxes)
+                        for b in boxes[i + 1:]))

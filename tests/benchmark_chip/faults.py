@@ -18,8 +18,8 @@ CKPT = {"fsdp": 64, "leaves": {
 SEED = 3_000_000_007
 
 
-def small_run(workload, tmp_path, *, traffic=None, seed=SEED):
-    cfg = CKPT if workload.startswith("phi3") else PIC
+def small_run(workload, tmp_path, *, cfg=None, traffic=None, seed=SEED):
+    cfg = cfg or (CKPT if workload.startswith("phi3") else PIC)
     return run(manifest.Manifest(ROOT), workload, seed, 0.5, False,
                devices=jax.devices(), overrides=(cfg, traffic or {}),
                work=tmp_path / "work")

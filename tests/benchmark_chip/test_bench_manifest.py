@@ -100,6 +100,31 @@ def test_each_cell_reports_what_its_metrics_move():
         assert changed == set(c["reduced"]), c["name"]
 
 
+def test_four_chip_cells_are_at_most_half_or_one():
+    four = [wl["name"] for wl in BENCH["workloads"] if wl["chips"] == 4]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 2), four
+
+
+@pytest.mark.parametrize("cell", ["phi3_fsdp64.reshard_4to1"])
+def test_a_layout_cell_is_found_by_name(cell):
+    """The cell's configuration and traffic are files found by name, its
+    traffic is save_restore's with the two layouts added, and it asks for
+    as many chips as its layouts use."""
+    man = manifest.Manifest(ROOT)
+    wl = man.workload(cell)
+    assert man.traffic_path(wl["traffic"]).is_file()
+    traffic = man.traffic(wl["traffic"])
+    base = man.traffic("save_restore")
+    layouts = {"save_chips", "restore_chips"}
+    assert {k: v for k, v in traffic.items() if k not in layouts | {"about"}} == {
+        k: v for k, v in base.items() if k != "about"}
+    assert max(traffic[k] for k in layouts) == wl["chips"]
+    e2e = {m["name"] for m in man.end_to_end(cell)}
+    assert e2e == {"ckpt_save_s", "ckpt_restore_s", "setup_s"}
+    assert {m["name"] for m in man.per_layer(cell)} == {
+        m["name"] for m in man.per_layer("phi3_fsdp64.save_restore")}
+
+
 def copy_benchmark(tmp_path):
     for p in BENCH["paths"]:
         shutil.copytree(ROOT / p, tmp_path / p,

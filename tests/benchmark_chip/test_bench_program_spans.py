@@ -14,9 +14,10 @@ from benchmarks.chip.run import per_layer
 PIC = "bit1_paper_share4.dump_every_chunk"
 DIAG = "bit1_paper_share4.diag_only"
 CKPT = "phi3_fsdp64.save_restore"
+RESHARD = "phi3_fsdp64.reshard_4to1"
 STEP = "jit_pic_run_chunk(3856464242902868826)"
 NEW = {"idle_unattributed_share.pic": [PIC],
-       "idle_unattributed_share.ckpt": [CKPT]}
+       "idle_unattributed_share.ckpt": [CKPT, RESHARD]}
 
 
 def view(modules, spans):
@@ -145,6 +146,7 @@ PARENT_TRACES = {
            [("bench.save", 1.0, 1.1), ("bench.wait", 1.1, 9.0)],
            {"saves": 1, "blocked_s": 8.0, "shuffled_bytes": 1 << 20}),
 }
+PARENT_TRACES[RESHARD] = PARENT_TRACES[CKPT]
 
 
 @pytest.mark.parametrize("workload", list(PARENT_TRACES))
